@@ -1,11 +1,10 @@
 """Pod-scale index construction path: TASTI with a *transformer backbone*
 embedder (the tasti-embedder config — swap in any of the 10 assigned archs),
-then the build_index launcher CLI.
+then the build_index launcher, called in this process (a child would need
+the chip this process already holds).
 
     PYTHONPATH=src python examples/pod_scale_index.py
 """
-import subprocess
-import sys
 import tempfile
 
 import numpy as np
@@ -14,6 +13,7 @@ from repro.core.embedder import EmbedderConfig
 from repro.core.pipeline import TastiConfig, build_tasti
 from repro.core.schema import make_workload
 from repro.core.triplet import TripletConfig
+from repro.launch import build_index
 
 
 def main() -> None:
@@ -30,12 +30,11 @@ def main() -> None:
           f"{sys_t.index.cost.target_invocations} target-DNN calls")
 
     with tempfile.TemporaryDirectory() as d:
-        cmd = [sys.executable, "-m", "repro.launch.build_index",
-               "--workload", "taipei", "--n-frames", "2000",
-               "--n-train", "150", "--n-reps", "300",
-               "--triplet-steps", "100", "--out", f"{d}/taipei_idx"]
-        print("+", " ".join(cmd))
-        subprocess.run(cmd, check=True)
+        argv = ["--workload", "taipei", "--n-frames", "2000",
+                "--n-train", "150", "--n-reps", "300",
+                "--triplet-steps", "100", "--out", f"{d}/taipei_idx"]
+        print("+ repro.launch.build_index", " ".join(argv))
+        build_index.main(argv)
 
 
 if __name__ == "__main__":

@@ -436,8 +436,8 @@ class QueryEngine:
                    topk_d2: np.ndarray, mode: str, n_classes: Optional[int],
                    version: int):
         """One propagation over a snapshot: fused device call when resident
-        scoring is on (falling back on a mid-compute crack or device error),
-        float64 numpy otherwise.  Returns ``(scores, source)`` with source
+        scoring is on (host path only after a mid-compute crack; a device
+        error raises), float64 numpy otherwise.  Returns ``(scores, source)`` with source
         in {"device", "host"} for span attribution."""
         if self.resident.enabled:
             out = self.resident.propagate(rep_scores, mode, version=version,

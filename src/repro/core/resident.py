@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import os
 import threading
-import warnings
 from typing import Optional
 
 import numpy as np
@@ -54,7 +53,7 @@ class ResidentIndexState:
     """
 
     def __init__(self, index, enabled: Optional[bool] = None,
-                 block_n: int = 256, obs=None):
+                 block_n: int = 1024, obs=None):
         self.index = index
         self.enabled = _default_enabled() if enabled is None else bool(enabled)
         self.block_n = int(block_n)
@@ -118,9 +117,9 @@ class ResidentIndexState:
                   n_classes: Optional[int] = None,
                   clip01: bool = False) -> Optional[np.ndarray]:
         """Fused device propagation of ``rep_scores`` (computed against index
-        ``version``) -> (N,) float64, or ``None`` to signal host fallback
-        (disabled, version raced with a crack, or a device failure — the
-        last also disables the resident path for the rest of the process).
+        ``version``) -> (N,) float64, or ``None`` when the host path must
+        answer (disabled, or the version raced with a crack).  A device or
+        compile error raises: it is never answered from the host.
         """
         if not self.enabled:
             self.stats["fallbacks"] += 1
@@ -128,19 +127,10 @@ class ResidentIndexState:
         if self.index.version != version:
             self.stats["fallbacks"] += 1
             return None          # crack landed since the caller snapshotted
-        try:
-            import jax.numpy as jnp
-            from repro.kernels.propagate.ops import propagate as _propagate
-            ids, d2 = self._structures(version)
-            out = _propagate(jnp.asarray(rep_scores, jnp.float32), ids, d2,
-                             mode, n_classes=n_classes, clip01=clip01,
-                             block_n=self.block_n)
-            return np.asarray(out, np.float64)
-        except Exception as e:                      # pragma: no cover - defensive
-            self.enabled = False
-            self.stats["fallbacks"] += 1
-            self.invalidate()
-            warnings.warn("device-resident proxy scoring failed "
-                          f"({type(e).__name__}: {e}); falling back to the "
-                          "host propagation path", RuntimeWarning)
-            return None
+        import jax.numpy as jnp
+        from repro.kernels.propagate.ops import propagate as _propagate
+        ids, d2 = self._structures(version)
+        out = _propagate(jnp.asarray(rep_scores, jnp.float32), ids, d2,
+                         mode, n_classes=n_classes, clip01=clip01,
+                         block_n=self.block_n)
+        return np.asarray(out, np.float64)

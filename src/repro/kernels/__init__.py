@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the TASTI hot path: distance_topk (index build and
+crack), fpf_update (representative selection) and propagate (serving).
+
+Each kernel package holds ``kernel.py`` (the Pallas kernel), ``ops.py`` (the
+jitted entry point that pads and picks an implementation) and ``ref.py``
+(the pure-jnp reference the parity tests compare against).
+"""
+
+
+def resolve_impl(impl: str) -> str:
+    """``"auto"`` -> the compiled Pallas kernel on a TPU, else the XLA
+    reference.  Interpret mode is a test tool, never an execution path."""
+    if impl != "auto":
+        return impl
+    import jax
+    return "pallas" if jax.devices()[0].platform == "tpu" else "xla"

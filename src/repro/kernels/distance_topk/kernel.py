@@ -8,11 +8,11 @@ grid step computes a (BN, BC) tile on the MXU (2 x BN x BC x D FLOPs via one
 ``dot``) and folds it into a per-row top-k held in VMEM across the j sweep —
 O(N*k) HBM writes instead of O(N*C).
 
-Top-k maintenance is sort-free (TPU-friendly): k rounds of (min, argmin,
-mask) extract the k smallest of the fresh tile, which are then merged with
-the running top-k through another k rounds over the concatenated 2k
-candidates.  All ops are VPU-native (max/where/iota); no lax.sort / top_k
-inside the kernel.
+Top-k maintenance is sort-free (TPU-friendly): k rounds of (min,
+first-index, mask) extract the k smallest of the fresh tile, which are then
+merged with the running top-k through another k rounds over both lists of k
+at once.  All ops are VPU-native (min/where/iota/sum); no sort, gather or
+dynamic index inside the kernel.
 """
 from __future__ import annotations
 
@@ -23,42 +23,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG_BIG = 3.0e38  # python scalar: jnp constants can't be captured by kernels
+_BIG_COL = 2 ** 30  # above every column index of a tile
 
 
-def _k_smallest(vals: jax.Array, ids: jax.Array, k: int):
-    """vals/ids (BN, M) -> k smallest per row, via k extraction rounds."""
-    bn = vals.shape[0]
+def _k_smallest(parts, k: int):
+    """k smallest per row of the column-wise concatenation of ``parts``.
+
+    ``parts`` is a sequence of (vals (BN, M_p) float32, ids (BN, M_p) int32);
+    returns (vals (BN, k), ids (BN, k)) ascending.  Ties go to the earlier
+    part, then the lower column — the order ``argmin`` over the
+    concatenation would give.  k static rounds of (min, first-index, mask),
+    all elementwise selects and lane reductions: no gather, no dynamic
+    index and no unaligned lane concatenation inside the kernel.
+    """
+    bn = parts[0][0].shape[0]
+    cols = [jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) for v, _ in parts]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1)
     out_v = jnp.zeros((bn, k), jnp.float32)
     out_i = jnp.zeros((bn, k), jnp.int32)
-
-    def body(t, carry):
-        vals_c, out_v, out_i = carry
-        m = jnp.min(vals_c, axis=1)
-        am = jnp.argmin(vals_c, axis=1)
-        sel = jnp.take_along_axis(ids, am[:, None], axis=1)[:, 0]
-        out_v = out_v.at[:, t].set(m)
-        out_i = out_i.at[:, t].set(sel)
-        onehot = jax.lax.broadcasted_iota(jnp.int32, vals_c.shape, 1) == am[:, None]
-        vals_c = jnp.where(onehot, NEG_BIG, vals_c)
-        return vals_c, out_v, out_i
-
-    _, out_v, out_i = jax.lax.fori_loop(0, k, body, (vals, out_v, out_i))
+    vals = [v for v, _ in parts]
+    for t in range(k):                                  # k static: unrolled
+        mins = [jnp.min(v, axis=1, keepdims=True) for v in vals]
+        m = functools.reduce(jnp.minimum, mins)         # (BN, 1)
+        taken = jnp.zeros((bn, 1), jnp.bool_)
+        sel = jnp.zeros((bn, 1), jnp.int32)
+        for p, ((_, ids), col) in enumerate(zip(parts, cols)):
+            here = jnp.logical_and(jnp.logical_not(taken), mins[p] == m)
+            first = jnp.min(jnp.where(vals[p] == m, col, _BIG_COL), axis=1,
+                            keepdims=True)
+            hit = jnp.logical_and(here, col == first)   # one column at most
+            sel = jnp.where(here, jnp.sum(jnp.where(hit, ids, 0), axis=1,
+                                          keepdims=True), sel)
+            vals[p] = jnp.where(hit, NEG_BIG, vals[p])
+            taken = jnp.logical_or(taken, here)
+        out_v = jnp.where(slot == t, m, out_v)
+        out_i = jnp.where(slot == t, sel, out_i)
     return out_v, out_i
 
 
-def _kernel(x_ref, r_ref, xsq_ref, rsq_ref, val_ref, idx_ref, *, k: int,
-            block_c: int):
+def _kernel(x_ref, r_ref, rsq_ref, val_ref, idx_ref, *, k: int, block_c: int):
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)          # (BN, D)
     r = r_ref[...].astype(jnp.float32)          # (BC, D)
-    d2 = (xsq_ref[...][:, None] + rsq_ref[...][None, :]
+    xsq = jnp.sum(x * x, axis=1, keepdims=True)  # (BN, 1)
+    d2 = (xsq + rsq_ref[...]
           - 2.0 * jax.lax.dot_general(
               x, r, (((1,), (1,)), ((), ())),
               preferred_element_type=jnp.float32))
     d2 = jnp.maximum(d2, 0.0)                   # (BN, BC)
     col_ids = (j * block_c
                + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1))
-    tile_v, tile_i = _k_smallest(d2, col_ids, k)
+    tile_v, tile_i = _k_smallest([(d2, col_ids)], k)
 
     @pl.when(j == 0)
     def _init():
@@ -67,9 +82,8 @@ def _kernel(x_ref, r_ref, xsq_ref, rsq_ref, val_ref, idx_ref, *, k: int,
 
     @pl.when(j > 0)
     def _merge():
-        cand_v = jnp.concatenate([val_ref[...], tile_v], axis=1)
-        cand_i = jnp.concatenate([idx_ref[...], tile_i], axis=1)
-        new_v, new_i = _k_smallest(cand_v, cand_i, k)
+        new_v, new_i = _k_smallest([(val_ref[...], idx_ref[...]),
+                                    (tile_v, tile_i)], k)
         val_ref[...] = new_v
         idx_ref[...] = new_i
 
@@ -84,8 +98,8 @@ def distance_topk_pallas(x: jax.Array, r: jax.Array, k: int,
     n, d = x.shape
     c = r.shape[0]
     assert n % block_n == 0 and c % block_c == 0, (n, c, block_n, block_c)
-    xsq = jnp.sum(x.astype(jnp.float32) ** 2, axis=1)
-    rsq = jnp.sum(r.astype(jnp.float32) ** 2, axis=1)
+    # (1, C): a lane-dense row; x's squared norms are summed in the kernel
+    rsq = jnp.sum(r.astype(jnp.float32) ** 2, axis=1)[None, :]
     grid = (n // block_n, c // block_c)
     return pl.pallas_call(
         functools.partial(_kernel, k=k, block_c=block_c),
@@ -93,8 +107,7 @@ def distance_topk_pallas(x: jax.Array, r: jax.Array, k: int,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((block_n, k), lambda i, j: (i, 0)),
@@ -105,4 +118,4 @@ def distance_topk_pallas(x: jax.Array, r: jax.Array, k: int,
             jax.ShapeDtypeStruct((n, k), jnp.int32),
         ],
         interpret=interpret,
-    )(x, r, xsq, rsq)
+    )(x, r, rsq)

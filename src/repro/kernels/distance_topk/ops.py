@@ -10,6 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_impl
 from repro.kernels.distance_topk.kernel import distance_topk_pallas
 from repro.kernels.distance_topk.ref import distance_topk_ref
 
@@ -58,8 +59,7 @@ def distance_topk(x: jax.Array, r: jax.Array, k: int, impl: str = "auto",
     tiling the worst *distance* instead would silently double-weight that
     rep in propagation.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    impl = resolve_impl(impl)
     k_eff = min(k, r.shape[0])
     if impl == "xla":
         d, i = distance_topk_ref(x, r, k_eff)

@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_impl
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
@@ -18,8 +19,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool = False) -> jax.Array:
     """q (B,S,H,hd), k/v (B,Skv,Hk,hd) -> (B,S,H,hd)."""
-    if impl == "auto":
-        impl = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    impl = resolve_impl(impl)
     if impl == "xla":
         return flash_attention_ref(q, k, v, causal=causal, window=window)
 

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_impl
 from repro.kernels.fpf_update.kernel import fpf_update_pallas
 from repro.kernels.fpf_update.ref import fpf_update_ref
 
@@ -14,8 +15,7 @@ from repro.kernels.fpf_update.ref import fpf_update_ref
 def fpf_update(x: jax.Array, rep: jax.Array, min_d2: jax.Array,
                impl: str = "auto", block_n: int = 1024,
                interpret: bool = False):
-    if impl == "auto":
-        impl = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    impl = resolve_impl(impl)
     if impl == "xla":
         return fpf_update_ref(x, rep, min_d2)
     n = x.shape[0]

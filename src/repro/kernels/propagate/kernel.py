@@ -23,13 +23,33 @@ from jax.experimental import pallas as pl
 
 # python scalar: jnp constants can't be captured by kernels
 PAD_DIST = 2.9e38
+#: reps per step of the in-kernel gather loop (lane-aligned); ops.py pads
+#: the rep-score row to a multiple of it
+CHUNK_C = 512
 
 
-def _gather(scores: jax.Array, ids: jax.Array, c: int) -> jax.Array:
-    """scores (C,), ids (BN,) -> scores[ids] via a one-hot reduction."""
-    onehot = ids[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (ids.shape[0], c), 1)
-    return jnp.sum(jnp.where(onehot, scores[None, :], 0.0), axis=1)
+def _gather_k(scores_ref, ids: jax.Array, cols) -> list:
+    """scores_ref (1, Cp), ids (BN, k) -> [scores[ids[:, j]] for j in cols].
+
+    One-hot reductions over (BN, CHUNK_C) comparison tiles, looped over the
+    rep axis so the kernel's size (and its compile time) does not grow with
+    C.  Exactly one rep matches each id, so adding the chunks' zeros leaves
+    every gathered value bit-identical to a single reduction.
+    """
+    bn = ids.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, CHUNK_C), 1)
+
+    def body(step, acc):
+        base = pl.multiple_of(step * CHUNK_C, CHUNK_C)
+        chunk = scores_ref[:, pl.ds(base, CHUNK_C)].astype(jnp.float32)
+        return tuple(
+            a + jnp.sum(jnp.where(ids[:, j:j + 1] == base + lane, chunk, 0.0),
+                        axis=1)
+            for a, j in zip(acc, cols))
+
+    n_steps = scores_ref.shape[1] // CHUNK_C
+    init = tuple(jnp.zeros((bn,), jnp.float32) for _ in cols)
+    return list(jax.lax.fori_loop(0, n_steps, body, init))
 
 
 def _column_weight(d2_col: jax.Array, eps: float) -> jax.Array:
@@ -38,16 +58,16 @@ def _column_weight(d2_col: jax.Array, eps: float) -> jax.Array:
     return jnp.where(d2_col >= PAD_DIST, 0.0, w)
 
 
-def _numeric_kernel(scores_ref, ids_ref, d2_ref, out_ref, *, k: int, c: int,
+def _numeric_kernel(scores_ref, ids_ref, d2_ref, out_ref, *, k: int,
                     eps: float, clip01: bool):
-    scores = scores_ref[...].astype(jnp.float32)     # (C,)
     ids = ids_ref[...]                               # (BN, k)
     d2 = d2_ref[...].astype(jnp.float32)             # (BN, k)
+    gathered = _gather_k(scores_ref, ids, range(k))
     num = jnp.zeros((ids.shape[0],), jnp.float32)
     den = jnp.zeros((ids.shape[0],), jnp.float32)
     for j in range(k):                               # k static: unrolled
         w = _column_weight(d2[:, j], eps)
-        num = num + w * _gather(scores, ids[:, j], c)
+        num = num + w * gathered[j]
         den = den + w
     out = num / den
     if clip01:
@@ -56,24 +76,23 @@ def _numeric_kernel(scores_ref, ids_ref, d2_ref, out_ref, *, k: int, c: int,
 
 
 def _categorical_kernel(scores_ref, ids_ref, d2_ref, out_ref, *, k: int,
-                        c: int, n_classes: int, eps: float):
-    scores = scores_ref[...].astype(jnp.float32)
+                        n_classes: int, eps: float):
     ids = ids_ref[...]
     d2 = d2_ref[...].astype(jnp.float32)
     bn = ids.shape[0]
+    gathered = _gather_k(scores_ref, ids, range(k))
     votes = jnp.zeros((bn, n_classes), jnp.float32)
     class_ids = jax.lax.broadcasted_iota(jnp.int32, (bn, n_classes), 1)
     for j in range(k):
         w = _column_weight(d2[:, j], eps)
-        cls = _gather(scores, ids[:, j], c).astype(jnp.int32)
+        cls = gathered[j].astype(jnp.int32)
         votes = votes + jnp.where(cls[:, None] == class_ids, w[:, None], 0.0)
     out_ref[...] = jnp.argmax(votes, axis=1).astype(jnp.float32)
 
 
-def _top1_kernel(scores_ref, ids_ref, d2_ref, pre_ref, out_ref, *, c: int,
+def _top1_kernel(scores_ref, ids_ref, d2_ref, pre_ref, out_ref, *,
                  clip01: bool):
-    scores = scores_ref[...].astype(jnp.float32)
-    base = _gather(scores, ids_ref[...][:, 0], c)
+    base, = _gather_k(scores_ref, ids_ref[...], [0])
     d = jnp.sqrt(jnp.maximum(d2_ref[...][:, 0].astype(jnp.float32), 0.0))
     out = base - pre_ref[0] * d
     if clip01:
@@ -84,36 +103,37 @@ def _top1_kernel(scores_ref, ids_ref, d2_ref, pre_ref, out_ref, *, c: int,
 def propagate_pallas(rep_scores: jax.Array, topk_ids: jax.Array,
                      topk_d2: jax.Array, mode: str, n_classes: int = 0,
                      clip01: bool = False, eps: float = 1e-6,
-                     prescale: jax.Array = None, block_n: int = 256,
+                     prescale: jax.Array = None, block_n: int = 1024,
                      interpret: bool = False) -> jax.Array:
     """rep_scores (C,), topk_ids/(d2) (N,k) -> (N,) propagated proxy.
 
-    N % block_n == 0 required (ops.py pads).  ``prescale`` is the top-1
-    tie-break scalar (a (1,) array; see
+    N % block_n == 0 and C % CHUNK_C == 0 required (ops.py pads; the 1-D
+    (block_n,) output blocks must be multiples of 1024 on a TPU).
+    ``prescale`` is the top-1 tie-break scalar (a (1,) array; see
     :func:`repro.kernels.propagate.ref.tie_break_prescale`) — it involves a
     global reduction over rows, so it is computed by XLA around the kernel.
     """
     n, k = topk_ids.shape
     c = rep_scores.shape[0]
-    assert n % block_n == 0, (n, block_n)
+    assert n % block_n == 0 and c % CHUNK_C == 0, (n, block_n, c)
     grid = (n // block_n,)
     common_specs = [
-        pl.BlockSpec((c,), lambda i: (0,)),              # full rep scores
+        pl.BlockSpec((1, c), lambda i: (0, 0)),          # full rep scores
         pl.BlockSpec((block_n, k), lambda i: (i, 0)),
         pl.BlockSpec((block_n, k), lambda i: (i, 0)),
     ]
     if mode == "numeric":
-        kernel = functools.partial(_numeric_kernel, k=k, c=c, eps=eps,
+        kernel = functools.partial(_numeric_kernel, k=k, eps=eps,
                                    clip01=clip01)
         operands = (rep_scores, topk_ids, topk_d2)
         in_specs = common_specs
     elif mode == "categorical":
-        kernel = functools.partial(_categorical_kernel, k=k, c=c,
+        kernel = functools.partial(_categorical_kernel, k=k,
                                    n_classes=n_classes, eps=eps)
         operands = (rep_scores, topk_ids, topk_d2)
         in_specs = common_specs
     elif mode == "top1":
-        kernel = functools.partial(_top1_kernel, c=c, clip01=clip01)
+        kernel = functools.partial(_top1_kernel, clip01=clip01)
         operands = (rep_scores, topk_ids, topk_d2, prescale)
         in_specs = common_specs + [pl.BlockSpec((1,), lambda i: (0,))]
     else:
@@ -125,4 +145,4 @@ def propagate_pallas(rep_scores: jax.Array, topk_ids: jax.Array,
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         interpret=interpret,
-    )(*operands)
+    )(rep_scores.reshape(1, c), *operands[1:])

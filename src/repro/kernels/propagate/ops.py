@@ -16,8 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_impl
 from repro.kernels.distance_topk.ops import PAD_DIST
-from repro.kernels.propagate.kernel import propagate_pallas
+from repro.kernels.propagate.kernel import CHUNK_C, propagate_pallas
 from repro.kernels.propagate.ref import (
     propagate_categorical_ref,
     propagate_numeric_ref,
@@ -62,6 +63,19 @@ def _propagate_impl(rep_scores, topk_ids, topk_d2, *, mode, n_classes, clip01,
     return out[:n]
 
 
+def _pad_reps(rep_scores: jax.Array) -> jax.Array:
+    """Pad (C,) rep scores to a multiple of ``CHUNK_C`` outside the jit, so
+    a crack that adds a few reps reuses the compiled program.  Ids never
+    point at the pad, and repeating the last score adds no positive gap, so
+    the top-1 tie-break scale is unchanged."""
+    pad = (-rep_scores.shape[0]) % CHUNK_C
+    if not pad:
+        return rep_scores
+    if rep_scores.shape[0] == 0:
+        return jnp.zeros((pad,), rep_scores.dtype)
+    return jnp.pad(rep_scores, (0, pad), mode="edge")
+
+
 _STATIC = ("mode", "n_classes", "clip01", "impl", "block_n", "interpret")
 _jit_plain = functools.partial(jax.jit, static_argnames=_STATIC)(
     _propagate_impl)
@@ -77,7 +91,7 @@ def _donation_ok() -> bool:
 
 def propagate(rep_scores: jax.Array, topk_ids: jax.Array, topk_d2: jax.Array,
               mode: str, n_classes: int | None = None, clip01: bool = False,
-              impl: str = "auto", block_n: int = 256,
+              impl: str = "auto", block_n: int = 1024,
               interpret: bool = False, donate: bool | None = None
               ) -> jax.Array:
     """Fused device propagation: rep_scores (C,) -> proxy scores (N,) f32.
@@ -92,10 +106,10 @@ def propagate(rep_scores: jax.Array, topk_ids: jax.Array, topk_d2: jax.Array,
         raise ValueError(f"unknown propagation mode {mode!r}")
     if mode == "categorical" and not n_classes:
         raise ValueError("categorical propagation needs n_classes")
-    if impl == "auto":
-        impl = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    impl = resolve_impl(impl)
     if topk_ids.shape[0] == 0:          # empty index: avoid 0-size jit/grid
         return jnp.zeros((0,), jnp.float32)
+    rep_scores = _pad_reps(jnp.asarray(rep_scores))
     fn = _jit_donate if (donate if donate is not None
                          else _donation_ok()) else _jit_plain
     return fn(rep_scores, topk_ids, topk_d2, mode=mode, n_classes=n_classes,

@@ -17,13 +17,14 @@ import argparse
 import json
 import time
 
-
 from repro.core.pipeline import TastiConfig, build_tasti
 from repro.core.schema import WORKLOAD_NAMES, make_workload
 from repro.core.triplet import TripletConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
-def main() -> None:
+def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="night-street",
                     choices=list(WORKLOAD_NAMES))
@@ -37,7 +38,7 @@ def main() -> None:
     ap.add_argument("--backbone", default="mlp",
                     help="'mlp' or a config name (e.g. tasti-embedder)")
     ap.add_argument("--out", required=True)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     wl = make_workload(args.workload, n_records=args.n_frames)
     cfg = TastiConfig(n_train=args.n_train, n_reps=args.n_reps, k=args.k,

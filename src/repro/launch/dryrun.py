@@ -53,10 +53,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, block: bool = False,
         return result
 
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-    else:  # jax < 0.5: ambient mesh via the context-manager protocol
-        mesh.__enter__()
+    jax.set_mesh(mesh)
     if block:
         cell = specs_lib.build_block_cell(cfg, shape, mesh, attn_impl=attn_impl)
     else:

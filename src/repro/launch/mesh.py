@@ -7,16 +7,10 @@ importing this module never touches jax device state; the dry-run sets
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -30,6 +30,7 @@ from repro.core.pipeline import build_tasti, cli_tasti_config
 from repro.core.queries.registry import registered_kinds
 from repro.core.schema import WORKLOAD_NAMES, make_workload
 from repro.core.session import QuerySession
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _load_specs(args) -> list:
@@ -51,6 +52,7 @@ def _load_specs(args) -> list:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="execute declarative QuerySpecs against a TASTI index")
     ap.add_argument("--workload", default="night-street",

@@ -39,6 +39,7 @@ import os
 import sys
 
 from repro.core.schema import WORKLOAD_NAMES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Observability
 from repro.serve.registry import WorkloadRegistry, WorkloadSpec
 from repro.serve.server import QueryServer
@@ -98,6 +99,7 @@ def _parse_mounts(args):
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="serve declarative QuerySpecs over HTTP, one workload "
                     "or many")

@@ -12,11 +12,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def largest_pow2_leq(n: int) -> int:
@@ -42,7 +38,5 @@ def make_elastic_mesh(n_devices: Optional[int] = None,
     import numpy as np
     arr = np.array(used).reshape(data, model)
     from jax.sharding import Mesh
-    if AxisType is None:
-        return Mesh(arr, ("data", "model"))
     return Mesh(arr, ("data", "model"),
                 axis_types=(AxisType.Auto, AxisType.Auto))

@@ -33,6 +33,7 @@ import dataclasses
 import json
 import sys
 import threading
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
@@ -126,6 +127,7 @@ class WorkloadEntry:
         self._lock = threading.Lock()        # serializes this entry's load
         self._load_error: Optional[Exception] = None
         self._obs: Optional[Observability] = None
+        self.load_seconds: Dict[str, float] = {}
         if obs is not None:
             self.adopt_obs(obs)
 
@@ -196,7 +198,9 @@ class WorkloadEntry:
 
     def _load(self) -> None:
         spec = self.spec
+        started = time.perf_counter()
         wl = make_workload(spec.dataset, n_records=spec.n_records)
+        generated = time.perf_counter()
         if spec.index:
             index = TastiIndex.load(spec.index)
             if index.n_records != len(wl.features):
@@ -211,6 +215,10 @@ class WorkloadEntry:
                                    n_reps=spec.n_reps, k=spec.k,
                                    triplet_steps=spec.triplet_steps)
             index = build_tasti(wl, cfg, variant=spec.variant).index
+        #: host-clock seconds of this load: dataset generation, then index
+        #: build or load
+        self.load_seconds = {"generate": generated - started,
+                             "index": time.perf_counter() - generated}
         scope = (self._obs.scoped(workload=self.name)
                  if self._obs is not None else None)
         engine = QueryEngine(index, wl, crack=spec.crack,

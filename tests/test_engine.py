@@ -231,7 +231,8 @@ def test_query_cli_smoke(tmp_path):
     import pathlib
     src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
     env = {**os.environ,
-           "PYTHONPATH": src + os.pathsep + os.environ.get("PYTHONPATH", "")}
+           "PYTHONPATH": src + os.pathsep + os.environ.get("PYTHONPATH", ""),
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")}
     cmd = [sys.executable, "-m", "repro.launch.query",
            "--workload", "night-street", "--n-frames", "800", "--quick",
            "--crack", "--save-index", str(tmp_path / "idx"),

@@ -24,8 +24,6 @@ def run_child(body: str) -> str:
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        if not hasattr(jax, "set_mesh"):  # jax < 0.5: ambient mesh via ctx
-            jax.set_mesh = lambda m: m.__enter__()
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,

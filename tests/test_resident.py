@@ -114,6 +114,25 @@ def test_version_mismatch_returns_none(setup):
     assert state.propagate(scores, "numeric", version=index.version) is not None
 
 
+def test_device_error_raises_instead_of_host_fallback(setup, monkeypatch):
+    """A device/compile failure in the fused call surfaces to the caller:
+    the resident path neither answers from the host nor disables itself."""
+    import repro.kernels.propagate.ops as prop_ops
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(prop_ops, "propagate", broken)
+    wl, index = setup
+    eng = QueryEngine(index, wl, resident=True)
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.proxy_scores("score_id")
+    assert eng.resident.enabled
+    assert eng.resident.stats["fallbacks"] == 0
+    assert eng.stats["proxy_device_computes"] == 0
+    assert eng.stats["propagation_computes"] == 0
+
+
 def test_disabled_state_is_inert(setup):
     wl, index = setup
     state = ResidentIndexState(index, enabled=False)
