@@ -192,8 +192,7 @@ class QueryEngine:
         # device-resident rep structures for the fused scoring hot path;
         # `resident=None` auto-enables on accelerators only (see
         # repro.core.resident for the policy and the env override)
-        self.resident = ResidentIndexState(index, enabled=resident,
-                                           obs=self.obs)
+        self.resident = ResidentIndexState(index, enabled=resident)
         self._broker = broker
         if broker is not None and obs is not None:
             broker.set_obs(self.obs)
@@ -312,8 +311,8 @@ class QueryEngine:
     def set_obs(self, obs) -> None:
         """Adopt an :class:`~repro.obs.ObsScope` after construction (the
         server wires a per-workload scope into engines registered before it
-        existed) and push it into the broker/pool/resident the engine
-        already built."""
+        existed) and push it into the broker and pool the engine already
+        built."""
         self.obs = obs if obs is not None else NULL_SCOPE
         with self._lock:
             broker, pool = self._broker, self._oracle_pool
@@ -321,7 +320,6 @@ class QueryEngine:
             broker.set_obs(self.obs)
         if pool is not None:
             pool.set_obs(self.obs)
-        self.resident.set_obs(self.obs)
 
     def add_stats(self, **deltas: int) -> None:
         """Atomically bump engine counters (dict ``+=`` is not)."""

@@ -53,7 +53,7 @@ class ResidentIndexState:
     """
 
     def __init__(self, index, enabled: Optional[bool] = None,
-                 block_n: int = 1024, obs=None):
+                 block_n: int = 1024):
         self.index = index
         self.enabled = _default_enabled() if enabled is None else bool(enabled)
         self.block_n = int(block_n)
@@ -67,13 +67,6 @@ class ResidentIndexState:
             "invalidations": 0,  # crack listeners dropping device state
             "fallbacks": 0,      # propagate() calls answered by the host path
         }
-        self.set_obs(obs)
-
-    def set_obs(self, obs) -> None:
-        """Attach an :class:`~repro.obs.ObsScope` (counters here stay in
-        ``self.stats`` and are exported at scrape time; nothing to resolve
-        eagerly — kept for interface symmetry with broker/pool)."""
-        self._obs = obs
 
     # ------------------------------------------------------------------
     def invalidate(self) -> None:

@@ -11,10 +11,14 @@ Disabled observability is the same object graph built on no-op parts
 (``NULL_REGISTRY``, a tracer handing out ``NULL_TRACE``), so call sites
 never branch on an enabled flag.  ``NULL_SCOPE`` is the default for every
 component's ``obs`` parameter.
+
+An enabled bundle also starts the process's one backend-compile listener
+(:data:`~repro.obs.trace.COMPILES`) and exports its per-function counts as
+``jax_backend_compiles_total{fun=...}``.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 from repro.obs import trace as trace_mod
 from repro.obs.metrics import (
@@ -22,9 +26,9 @@ from repro.obs.metrics import (
     parse_prometheus_text, series_key,
 )
 from repro.obs.trace import (
-    NULL_SPAN, NULL_TRACE, FlightRecorder, Span, Trace, Tracer, activate,
-    active_trace, add_timed_span, chrome_trace, chrome_traces, new_trace_id,
-    span, start_span,
+    COMPILES, NULL_SPAN, NULL_TRACE, FlightRecorder, Span, Trace, Tracer,
+    activate, active_trace, add_timed_span, chrome_trace, new_trace_id, span,
+    start_span,
 )
 
 __all__ = [
@@ -33,7 +37,7 @@ __all__ = [
     "series_key", "LATENCY_BUCKETS", "SIZE_BUCKETS",
     "Tracer", "Trace", "Span", "FlightRecorder", "NULL_TRACE", "NULL_SPAN",
     "activate", "active_trace", "span", "start_span", "add_timed_span",
-    "chrome_trace", "chrome_traces", "new_trace_id",
+    "chrome_trace", "new_trace_id", "COMPILES",
 ]
 
 
@@ -47,6 +51,8 @@ class Observability:
             self.recorder: Optional[FlightRecorder] = \
                 FlightRecorder(trace_buffer)
             self.tracer = Tracer(self.recorder, enabled=True)
+            COMPILES.install()
+            self.metrics.add_collector(_compile_samples)
         else:
             self.metrics = NULL_REGISTRY
             self.recorder = None
@@ -66,6 +72,12 @@ class Observability:
     def histogram(self, name: str, help: str = "",
                   buckets: Optional[Iterable[float]] = None, **labels: Any):
         return self.metrics.histogram(name, help, buckets=buckets, **labels)
+
+
+def _compile_samples() -> List[Sample]:
+    return [Sample("jax_backend_compiles_total", n, "counter", {"fun": fun},
+                   "backend compiles per jitted function (jax.monitoring)")
+            for fun, n in COMPILES.counts()]
 
 
 class ObsScope:

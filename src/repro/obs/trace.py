@@ -17,6 +17,19 @@ postmortems — and can be exported as Chrome trace-event JSON
 Span timestamps are ``time.perf_counter()`` values (monotonic, comparable
 across threads on one host); each trace also records the wall-clock epoch
 at which it started so exports can be anchored to real time.
+
+Spans used as context managers (every :func:`span`) also record the
+thread's CPU time (``attrs["cpu_s"]``, from ``time.thread_time()``): wall
+time less ``cpu_s`` is time the thread spent off the CPU — the GIL, locks,
+device or oracle waits.  While a JAX profiler session is active they are
+mirrored into the profiler's own trace as ``TraceAnnotation`` events of
+the same name carrying the request's ``trace_id``, so a captured profile
+shows the request spans on the profiler's clock beside the device ops.
+With the profiler off that costs one ``TraceAnnotation.is_enabled()``.
+
+:data:`COMPILES` watches JAX's backend compiles: each lands on the
+compiling thread's active trace as a ``jax.compile`` span and is counted
+per function for ``/metrics``.
 """
 from __future__ import annotations
 
@@ -25,12 +38,15 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span", "Trace", "Tracer", "FlightRecorder", "NULL_SPAN", "NULL_TRACE",
     "new_trace_id", "span", "start_span", "add_timed_span", "activate",
-    "active_trace", "chrome_trace",
+    "active_trace", "chrome_trace", "CompileWatch", "COMPILES",
 ]
 
 _tls = threading.local()
@@ -51,7 +67,8 @@ class Span:
     via explicit :meth:`end` when the operation doesn't nest lexically
     (e.g. the scheduler queue span, ended at grant on another thread)."""
 
-    __slots__ = ("name", "span_id", "parent_id", "t0", "t1", "attrs", "thread")
+    __slots__ = ("name", "span_id", "parent_id", "t0", "t1", "attrs", "thread",
+                 "_cpu0", "_annotation")
 
     def __init__(self, name: str, span_id: int, parent_id: Optional[int],
                  t0: Optional[float] = None,
@@ -63,6 +80,8 @@ class Span:
         self.t1: Optional[float] = None
         self.attrs: Dict[str, Any] = attrs or {}
         self.thread = threading.get_ident()
+        self._cpu0: Optional[float] = None
+        self._annotation: Optional[TraceAnnotation] = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -78,11 +97,23 @@ class Span:
                 - self.t0)
 
     # context-manager protocol (manual __enter__/__exit__: cheaper than
-    # @contextmanager and exception-safe)
+    # @contextmanager and exception-safe); a lexically nested span runs on
+    # one thread, so its CPU time and profiler annotation are taken here
     def __enter__(self) -> "Span":
+        if TraceAnnotation.is_enabled():
+            trace = getattr(_tls, "trace", None)
+            self._annotation = TraceAnnotation(
+                self.name, trace_id=trace.trace_id if trace else "")
+            self._annotation.__enter__()
+        self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._cpu0 is not None:
+            self.attrs["cpu_s"] = time.thread_time() - self._cpu0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if exc is not None and "error" not in self.attrs:
             self.attrs["error"] = f"{type(exc).__name__}: {exc}"
         self.end()
@@ -223,6 +254,8 @@ class _NullTrace:
     attrs: Dict[str, Any] = {}
     spans: List[Span] = []
     root = NULL_SPAN
+    t0 = 0.0
+    t1 = 0.0
     finished = True
     duration_s = 0.0
 
@@ -319,6 +352,54 @@ def add_timed_span(name: str, t0: float, t1: float, **attrs: Any):
     stack = getattr(_tls, "stack", None)
     parent = stack[-1].span_id if stack else 0
     return trace.add_timed_span(name, t0, t1, parent_id=parent, **attrs)
+
+
+# ---------------------------------------------------------------------------
+# backend compiles
+
+class CompileWatch:
+    """Backend compiles as JAX reports them.  One ``jax.monitoring``
+    time-span listener per process (JAX's listener list is process-wide,
+    and so are compiles): each compile becomes a ``jax.compile`` span with
+    a ``fun`` attribute on the compiling thread's active trace, if any, and
+    bumps a per-function count that every enabled
+    :class:`~repro.obs.Observability` exports as
+    ``jax_backend_compiles_total{fun=...}``."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._counts: Dict[str, int] = {}
+        self._installed = False
+
+    def install(self) -> None:
+        """Register the listener (idempotent)."""
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        jax.monitoring.register_event_time_span_listener(self._on_span)
+
+    def _on_span(self, event: str, start_time: float, end_time: float,
+                 **kwargs: Any) -> None:
+        if event != self.EVENT:
+            return
+        fun = str(kwargs.get("fun_name", ""))
+        with self._lock:
+            self._counts[fun] = self._counts.get(fun, 0) + 1
+        # JAX times compiles on time.time(); spans run on perf_counter
+        shift = time.perf_counter() - time.time()
+        add_timed_span("jax.compile", start_time + shift, end_time + shift,
+                       fun=fun)
+
+    def counts(self) -> List[Tuple[str, int]]:
+        """[(function name, backend compiles)] since the listener began."""
+        with self._lock:
+            return sorted(self._counts.items())
+
+
+COMPILES = CompileWatch()
 
 
 # ---------------------------------------------------------------------------
@@ -421,18 +502,3 @@ def chrome_trace(trace: Trace) -> Dict[str, Any]:
             **{f"attr_{k}": v for k, v in trace.attrs.items()},
         },
     }
-
-
-def chrome_traces(traces: Iterable[Trace]) -> Dict[str, Any]:
-    """Merge several traces into one Chrome trace document (one ``pid``
-    per trace so they stack as separate process tracks)."""
-    events: List[Dict[str, Any]] = []
-    meta: List[Dict[str, Any]] = []
-    for pid, t in enumerate(traces, start=1):
-        doc = chrome_trace(t)
-        for ev in doc["traceEvents"]:
-            ev["pid"] = pid
-        events.extend(doc["traceEvents"])
-        meta.append(doc["otherData"])
-    return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"traces": meta}}

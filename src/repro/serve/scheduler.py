@@ -78,6 +78,10 @@ class ScheduledTask:
     budget: Optional[int] = None      # budgeted tasks are never merged
     enqueued_at: float = 0.0
     ready_at: float = 0.0             # arrival + admission window (coalescible)
+    # per submission (absorbed ones included), its ready_at on the spans'
+    # perf_counter clock; and the first grant on that clock
+    ready_pcs: List[float] = field(default_factory=list)
+    grant_pc: Optional[float] = None
     seq: int = 0                      # admission order (final tie-break)
     # scheduler-managed state, guarded by the scheduler's condition lock
     state: str = "waiting"            # waiting|running|paused|done
@@ -238,6 +242,7 @@ class QueryScheduler:
         if not task.started:
             task.started = True
             task.first_grant_at = now
+            task.grant_pc = time.perf_counter()
             self.stats["granted"] += 1
             self._record_wait(ws, now - task.enqueued_at)
             # coalesce at grant: absorb every waiting same-workload,
@@ -251,6 +256,7 @@ class QueryScheduler:
                         t.absorbed = True
                         self._waiting.remove(t)
                         task.submissions.extend(t.submissions)
+                        task.ready_pcs.extend(t.ready_pcs)
                         if t.deadline is not None:
                             task.deadline = (t.deadline if task.deadline is None
                                              else min(task.deadline, t.deadline))
@@ -272,12 +278,13 @@ class QueryScheduler:
     def submit(self, task: ScheduledTask) -> ScheduledTask:
         """Enqueue a task and start its thread.  Non-blocking; after
         shutdown the task fails 503 on its own thread (never stranded)."""
-        now = time.monotonic()
+        now, now_pc = time.monotonic(), time.perf_counter()
         task.enqueued_at = now
         if task.budget is None and self.admission_window > 0:
             task.ready_at = now + self.admission_window
         else:
             task.ready_at = now
+        task.ready_pcs = [now_pc + task.ready_at - now] * len(task.submissions)
         with self._cond:
             self._seq += 1
             task.seq = self._seq

@@ -397,6 +397,23 @@ class QueryServer:
         trace.set(**attrs)
         self.obs.tracer.finish(trace)
 
+    @staticmethod
+    def _end_queue_span(sub: _Submission, ready: float, grant: float) -> None:
+        """End the submission's ``sched.queue`` span at its grant and split
+        it in two children: ``sched.hold``, until the admission window let
+        it run (or the grant, if that came first: absorbed co-travelers),
+        and ``sched.slot``, ready but waiting for a free slot."""
+        queue = sub.queue_span
+        if queue is None or sub.trace is None:
+            return
+        queue.end(grant)
+        ready = min(max(ready, queue.t0), grant)
+        sub.trace.add_timed_span("sched.hold", queue.t0, ready,
+                                 parent_id=queue.span_id)
+        if grant > ready:
+            sub.trace.add_timed_span("sched.slot", ready, grant,
+                                     parent_id=queue.span_id)
+
     def _fail_batch(self, workload: str, batch: List[_Submission],
                     e: Exception, status: int) -> None:
         self._bump(workload, errors=1)
@@ -414,9 +431,8 @@ class QueryServer:
         # absorbed co-travelers close their queue span here and their root
         # points at the primary trace that answered them
         primary_trace = batch[0].trace
-        for sub in batch:
-            if sub.queue_span is not None:
-                sub.queue_span.end()
+        for sub, ready in zip(batch, task.ready_pcs):
+            self._end_queue_span(sub, ready, task.grant_pc)
         if primary_trace is not None:
             for sub in batch[1:]:
                 if sub.trace is not None:
@@ -792,6 +808,7 @@ class _Handler(BaseHTTPRequestHandler):
             self._reply(404, {"error": f"unknown path {self.path}"})
 
     def do_POST(self) -> None:
+        received = time.perf_counter()
         if self.path == "/shutdown":
             self._reply(200, {"ok": True, "shutting_down": True})
             # a fresh NON-daemon thread: shutdown() joins the serving threads
@@ -838,21 +855,28 @@ class _Handler(BaseHTTPRequestHandler):
         except RuntimeError as e:
             self._reply(503, {"error": str(e)})
             return
+        # the front end's own spans sit outside the root, which keeps its
+        # admission-to-answer duration: reading and parsing the request up
+        # to submit, then encoding and writing the answer after the trace
+        # has finished
+        trace = sub.trace
+        trace.add_timed_span("http.read", received, trace.t0)
         if not sub.done.wait(timeout=self.owner.request_timeout):
             self._reply(504, {"error": "query timed out in the session pool"})
             return
         if sub.error is not None:
             self._reply(sub.status, {"error": sub.error})
-            return
-        self._reply(200, {
-            "results": sub.rows,
-            "session": sub.session,
-            "request": {
-                "workload": sub.workload,
-                "n_specs": len(sub.rows),
-                "fresh": sum(r["n_oracle_fresh"] for r in sub.rows),
-                "cached": sum(r["n_oracle_cached"] for r in sub.rows),
-                # "" when tracing is off (NULL_TRACE) -> omit as None
-                "trace_id": getattr(sub.trace, "trace_id", None) or None,
-            },
-        })
+        else:
+            self._reply(200, {
+                "results": sub.rows,
+                "session": sub.session,
+                "request": {
+                    "workload": sub.workload,
+                    "n_specs": len(sub.rows),
+                    "fresh": sum(r["n_oracle_fresh"] for r in sub.rows),
+                    "cached": sum(r["n_oracle_cached"] for r in sub.rows),
+                    # "" when tracing is off (NULL_TRACE) -> omit as None
+                    "trace_id": getattr(sub.trace, "trace_id", None) or None,
+                },
+            })
+        trace.add_timed_span("http.write", trace.t1, time.perf_counter())

@@ -1,10 +1,13 @@
 """Observability subsystem tests: metrics registry round-trip (render ->
 parse), tracer span nesting + the bounded flight recorder, Chrome trace
-export, disabled-path no-ops, loadgen error-kind classification and trace
-stamping, and the end-to-end attribution guarantee over a live server —
-every fresh oracle label of a traced request lands in exactly one span
-chain, and the ``/metrics`` exposition agrees with the request's own
-accounting."""
+export, disabled-path no-ops, CPU time on nested spans, their export to the
+JAX profiler, backend compiles on the trace, loadgen error-kind
+classification and trace stamping, the scheduler wait split into hold and
+slot, the front end's own spans, and the end-to-end attribution guarantee
+over a live server — every fresh oracle label of a traced request lands in
+exactly one span chain, and the ``/metrics`` exposition agrees with the
+request's own accounting."""
+import glob
 import threading
 import time
 
@@ -207,6 +210,139 @@ def test_scoped_labels_fold_into_instruments():
                              replica=1)] == 1
 
 
+def test_nested_spans_record_the_threads_cpu_time():
+    trace = Trace("request")
+    with activate(trace):
+        with span("busy") as busy:
+            t_end = time.perf_counter() + 0.05
+            while time.perf_counter() < t_end:
+                pass
+        with span("asleep") as asleep:
+            time.sleep(0.05)
+    trace.finish()
+    # a busy loop holds the CPU for its whole wall time; a sleep for none
+    assert abs(busy.attrs["cpu_s"] - busy.duration_s) < 0.01
+    assert asleep.attrs["cpu_s"] < 0.005 < 0.045 < asleep.duration_s
+    # spans not entered as context managers stay wall-time only
+    loose = trace.new_span("loose")
+    loose.end()
+    assert "cpu_s" not in loose.attrs
+
+
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts the checks
+    and records every annotation opened."""
+
+    enabled = False
+    checks = 0
+    opened: list = []
+
+    @classmethod
+    def is_enabled(cls):
+        cls.checks += 1
+        return cls.enabled
+
+    def __init__(self, name, **kwargs):
+        self.opened.append((name, kwargs))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+def test_with_the_profiler_off_a_span_only_checks_it(monkeypatch):
+    fake = type("Fake", (_RecordingAnnotation,), {"opened": []})
+    monkeypatch.setattr("repro.obs.trace.TraceAnnotation", fake)
+    trace = Trace("request", trace_id="d" * 16)
+    with activate(trace):
+        with span("session.plan"):
+            with span("spec.execute"):
+                pass
+    assert fake.checks == 2 and fake.opened == []
+    fake.enabled = True
+    with activate(trace):
+        with span("session.execute"):
+            pass
+    assert fake.opened == [("session.execute", {"trace_id": "d" * 16})]
+    # disabled observability: no span object, no check at all
+    with activate(NULL_TRACE):
+        with span("session.plan"):
+            pass
+    assert fake.checks == 3
+
+
+def test_a_served_requests_spans_land_on_the_profilers_host_plane(
+        wl, index, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    server = QueryServer(QueryEngine(index, wl), port=0,
+                         admission_window=0.0, max_workers=2).start()
+    try:
+        specs = [QuerySpec.from_dict(dict(d)) for d in SPEC_DICTS]
+        quiet = server.submit(specs, trace_id="e" * 16)
+        assert quiet.done.wait(60) and quiet.error is None
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            sub = server.submit(specs, trace_id="f" * 16)
+            assert sub.done.wait(60) and sub.error is None
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        server.shutdown()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                tid = dict(e.stats).get("trace_id")
+                if tid is not None:
+                    found.setdefault(tid, set()).add(
+                        (plane.name, i, e.name))
+    # only the request traced while the profiler ran, on the host plane,
+    # each nested span once per time it ran
+    assert set(found) == {"f" * 16}
+    names = {name for _, _, name in found["f" * 16]}
+    assert {"session.plan", "session.execute", "spec.execute"} <= names
+    assert all(plane.startswith("/host") for plane, _, _ in found["f" * 16])
+    # timed and manually ended spans stay off the profiler
+    assert not names & {"request", "sched.queue", "sched.hold",
+                        "oracle.subbatch"}
+
+
+def test_compiles_land_on_the_active_trace_and_in_metrics():
+    import jax
+    import jax.numpy as jnp
+    obs = Observability()
+    key = series_key("jax_backend_compiles_total", fun="jit(tripled)")
+    x = jnp.ones(7)
+
+    def tripled(v):
+        return v * 3.0 + 1.0
+
+    before = parse_prometheus_text(obs.metrics.render()).get(key, 0.0)
+    trace = Trace("request")
+    t0 = time.perf_counter()
+    with activate(trace):
+        with span("proxy.materialize") as parent:
+            jax.jit(tripled)(x).block_until_ready()
+    trace.finish()
+    (compile_span,) = trace.find_spans("jax.compile")
+    assert compile_span.attrs["fun"] == "jit(tripled)"
+    assert compile_span.parent_id == parent.span_id
+    # put on the spans' clock: inside the span that compiled
+    assert t0 - 0.01 < compile_span.t0 <= compile_span.t1 \
+        < trace.t1 + 0.01
+    after = parse_prometheus_text(obs.metrics.render())
+    assert after[key] == before + 1
+    # a compile outside any trace is counted, and lands on no trace
+    jax.jit(tripled)(jnp.ones(9)).block_until_ready()
+    assert parse_prometheus_text(obs.metrics.render())[key] == before + 2
+
+
 # -- loadgen error kinds + trace stamping ----------------------------------
 def test_error_kind_classification():
     assert _classify_error(ServerError("bad spec", status=400)) == "http_4xx"
@@ -311,6 +447,18 @@ def test_broker_observe_is_consistent_under_concurrent_flush(wl, index):
 
 
 # -- live server: end-to-end attribution -----------------------------------
+def _finished_trace(client, tid: str, timeout: float = 10.0) -> dict:
+    """The request's trace once its handler has added ``http.write``, which
+    happens just after the client has read the answer."""
+    deadline = time.monotonic() + timeout
+    while True:
+        doc = client.traces(trace_id=tid)
+        if any(s["name"] == "http.write" for s in doc["spans"]):
+            return doc
+        assert time.monotonic() < deadline, "no http.write span"
+        time.sleep(0.01)
+
+
 def test_traced_request_attributes_every_fresh_label(wl, index):
     """The acceptance invariant: with a replicated oracle pool, a traced
     request's fresh count, the sum over its ``broker.flush`` spans, the sum
@@ -331,7 +479,7 @@ def test_traced_request_attributes_every_fresh_label(wl, index):
         fresh = req["fresh"]
         assert fresh > 0
 
-        doc = client.traces(trace_id=tid)
+        doc = _finished_trace(client, tid)
         assert doc["trace_id"] == tid
         spans = doc["spans"]
         by_id = {s["span_id"]: s for s in spans}
@@ -378,6 +526,87 @@ def test_traced_request_attributes_every_fresh_label(wl, index):
         assert ei.value.status == 404
     finally:
         server.shutdown()
+
+
+def test_front_end_spans_sit_outside_the_root(wl, index):
+    server = QueryServer(QueryEngine(index, wl), port=0,
+                         admission_window=0.0, max_workers=2).start()
+    try:
+        client = QueryClient(server.url)
+        client.wait_ready(30)
+        tid = "feedfacecafe0002"
+        client.query(SPEC_DICTS[:1], trace_id=tid)
+        doc = _finished_trace(client, tid)
+    finally:
+        server.shutdown()
+    by_name = {s["name"]: s for s in doc["spans"]}
+    root, read, write = (by_name[n] for n in
+                         ("request", "http.read", "http.write"))
+    assert read["parent_id"] == write["parent_id"] == 0
+    # reading ends where the root begins and writing starts where it ends,
+    # so the root keeps its admission-to-answer duration
+    assert read["t0"] < read["t1"] == root["t0"]
+    assert root["t1"] == write["t0"] < write["t1"]
+    assert doc["duration_s"] == pytest.approx(root["t1"] - root["t0"])
+
+
+def _queue_split(trace):
+    (queue,) = trace.find_spans("sched.queue")
+    (hold,) = trace.find_spans("sched.hold")
+    slots = trace.find_spans("sched.slot")
+    assert hold.parent_id == queue.span_id
+    assert all(s.parent_id == queue.span_id for s in slots)
+    assert len(slots) <= 1
+    slot = slots[0].duration_s if slots else 0.0
+    assert abs(hold.duration_s + slot - queue.duration_s) < 1e-3
+    return hold.duration_s, slot
+
+
+def test_the_queue_wait_splits_into_hold_and_slot(wl, index):
+    window = 0.2
+    server = QueryServer(QueryEngine(index, wl), port=0,
+                         admission_window=window, max_workers=2).start()
+    try:
+        specs = [QuerySpec.from_dict(dict(SPEC_DICTS[0]))]
+        first = server.submit(specs)
+        time.sleep(0.05)
+        rider = server.submit(specs)
+        for sub in (first, rider):
+            assert sub.done.wait(60) and sub.error is None
+    finally:
+        server.shutdown()
+    # the first request is held for the whole window, then granted at once
+    hold, slot = _queue_split(first.trace)
+    assert window - 1e-3 < hold < window + 0.05
+    # the rider is absorbed at that grant, before its own window is over:
+    # held until then, never waiting for a slot
+    assert rider.trace.attrs["coalesced_into"] == first.trace.trace_id
+    hold, slot = _queue_split(rider.trace)
+    assert 0.0 < hold < window and slot == 0.0
+
+
+def test_a_ready_request_waiting_for_a_slot_shows_sched_slot(wl, index):
+    server = QueryServer(QueryEngine(index, wl), port=0,
+                         admission_window=0.0, max_workers=1).start()
+    run_batch = server._run_batch
+
+    def slow_run_batch(task, entry):
+        time.sleep(0.1)
+        run_batch(task, entry)
+
+    server._run_batch = slow_run_batch
+    server._scheduler._run = slow_run_batch
+    try:
+        specs = [QuerySpec.from_dict(dict(SPEC_DICTS[0]))]
+        first, second = server.submit(specs), server.submit(specs)
+        for sub in (first, second):
+            assert sub.done.wait(60) and sub.error is None
+    finally:
+        server.shutdown()
+    assert _queue_split(first.trace)[0] < 0.05
+    # ready at once, but the one slot is held by the first request's run
+    hold, slot = _queue_split(second.trace)
+    assert hold < 0.05 and slot > 0.05
 
 
 def test_server_with_observability_disabled_still_serves(wl, index):
