@@ -31,7 +31,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Union
+from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -40,6 +40,7 @@ from repro.core import propagation, queries as _queries, schema as schema_lib  #
 from repro.core.broker import OracleAccount, OracleBroker
 from repro.core.index import TastiIndex
 from repro.core.oracle_pool import OraclePool
+from repro.core.queries.aggregation import stratified_order
 from repro.core.queries.registry import QueryExecutor, get_executor
 from repro.core.resident import ResidentIndexState
 from repro.obs import NULL_SCOPE
@@ -187,6 +188,8 @@ class QueryEngine:
         self.max_oracle_batch = int(max_oracle_batch)
         self._proxy_cache: Dict[Any, np.ndarray] = {}
         self._proxy_cache_version = index.version
+        # stratified sample orders, one per proxy key: (tag, read-only order)
+        self._order_cache: Dict[Any, Tuple[Tuple, np.ndarray]] = {}
         # in-flight propagations (single-flight): key -> Event set on finish
         self._proxy_flights: Dict[Any, threading.Event] = {}
         # device-resident rep structures for the fused scoring hot path;
@@ -228,6 +231,8 @@ class QueryEngine:
             "proxy_cache_hits": 0,
             "proxy_device_computes": 0,
             "proxy_flight_waits": 0,
+            "sample_order_computes": 0,
+            "sample_order_hits": 0,
             "label_fresh": 0,
             "label_cache_hits": 0,
             "cracked_records": 0,
@@ -385,9 +390,7 @@ class QueryEngine:
         key = (self._cache_key(score, score_key), mode, n_classes)  # bad specs
         while True:
             with self._lock:
-                if self._proxy_cache_version != self.index.version:
-                    self._proxy_cache.clear()
-                    self._proxy_cache_version = self.index.version
+                self._drop_stale_memos()
                 if key in self._proxy_cache:
                     self.stats["proxy_cache_hits"] += 1
                     return self._proxy_cache[key]
@@ -429,6 +432,49 @@ class QueryEngine:
                     self._proxy_cache[key] = out
                     return out
             # cracked mid-compute: result is stale, go around again
+
+    def _drop_stale_memos(self) -> None:
+        """Clear the proxy and sample-order memos once the index version
+        has moved on (a crack).  Call under ``self._lock``."""
+        if self._proxy_cache_version != self.index.version:
+            self._proxy_cache.clear()
+            self._order_cache.clear()
+            self._proxy_cache_version = self.index.version
+
+    def sample_order(self, plan: QueryPlan, n_strata: int = 10,
+                     seed: int = 0) -> Tuple[np.ndarray, bool]:
+        """The stratified sample order over ``plan``'s proxy (see
+        :func:`~repro.core.queries.aggregation.stratified_order`) and whether
+        it came from the memo.
+
+        The memo sits beside the proxy cache and is keyed like it: one entry
+        per (score, mode, n_classes), tagged with the arguments it was built
+        from; a call with others recomputes and replaces the entry.  A crack
+        clears it with the proxy cache, and an order computed across a crack
+        is not stored.  The order is computed outside the lock (a race of
+        first uses computes it twice, identically) and returned read-only,
+        since concurrent sessions share it.  External proxies are never
+        memoized."""
+        spec = plan.spec
+        if spec.proxy is not None or plan.score_key is None:
+            return stratified_order(self.proxy_for(plan), n_strata,
+                                    seed), False
+        key = (plan.score_key, plan.propagation, spec.n_classes)
+        tag = (int(n_strata), int(seed), plan.clip01)
+        with self._lock:
+            self._drop_stale_memos()
+            entry = self._order_cache.get(key)
+            if entry is not None and entry[0] == tag:
+                self.stats["sample_order_hits"] += 1
+                return entry[1], True
+            version = self.index.version
+        order = stratified_order(self.proxy_for(plan), n_strata, seed)
+        order.flags.writeable = False
+        with self._lock:
+            self.stats["sample_order_computes"] += 1
+            if self.index.version == version:
+                self._order_cache[key] = (tag, order)
+        return order, False
 
     def _propagate(self, rep_scores: np.ndarray, topk_ids: np.ndarray,
                    topk_d2: np.ndarray, mode: str, n_classes: Optional[int],

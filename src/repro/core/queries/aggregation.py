@@ -45,6 +45,31 @@ def sample_order(n: int, seed: int,
     return np.random.default_rng(seed).permutation(n)
 
 
+def stratified_order(proxy: np.ndarray, n_strata: int = 10,
+                     seed: int = 0) -> np.ndarray:
+    """A full permutation of record ids whose every prefix is (approximately)
+    stratified over ``n_strata`` equal-frequency proxy-score strata.
+
+    Records are ranked by proxy score, split into equal-sized strata,
+    shuffled within each stratum, and interleaved round-robin — so any
+    prefix covers the proxy range evenly.  Aggregation specs sharing this
+    order draw nested, stratified samples."""
+    n = len(proxy)
+    n_strata = max(1, min(int(n_strata), n))
+    rng = np.random.default_rng(seed)
+    ranks = np.argsort(np.argsort(proxy, kind="stable"), kind="stable")
+    strata = (ranks * n_strata) // n                  # (n,) stratum per record
+    perm = rng.permutation(n)
+    sp = strata[perm]
+    within = np.empty(n, np.int64)
+    for s in range(n_strata):
+        members = np.where(sp == s)[0]
+        within[members] = np.arange(len(members))
+    round_pos = rng.permutation(n_strata)             # stratum order per round
+    key = within * n_strata + round_pos[sp]
+    return perm[np.argsort(key, kind="stable")]
+
+
 def first_sample_size(n: int, min_samples: int,
                       max_samples: Optional[int]) -> int:
     """Size of the first (deterministic) oracle batch of the EB loop."""

@@ -10,7 +10,8 @@ cross-query structure explicit instead of incidental:
 * **shared stratified sample** — aggregation specs in a group walk one
   sample order whose every prefix is stratified over proxy-score strata, so
   their samples *nest*: the group's fresh-label cost is the max of its
-  members, not the sum;
+  members, not the sum; the engine builds the order once per index version
+  and later sessions over the score reuse it;
 * **prefetch + combined flush** — each executor previews the ids it will
   certainly request first; the session enqueues all previews through the
   :class:`~repro.core.broker.OracleBroker` and flushes once, so one
@@ -38,31 +39,6 @@ import numpy as np
 from repro.core.broker import OracleAccount
 from repro.core.engine import QueryEngine, QueryPlan, QueryResult, QuerySpec
 from repro.obs.trace import span as trace_span
-
-
-def stratified_order(proxy: np.ndarray, n_strata: int = 10,
-                     seed: int = 0) -> np.ndarray:
-    """A full permutation of record ids whose every prefix is (approximately)
-    stratified over ``n_strata`` equal-frequency proxy-score strata.
-
-    Records are ranked by proxy score, split into equal-sized strata,
-    shuffled within each stratum, and interleaved round-robin — so any
-    prefix covers the proxy range evenly.  Aggregation specs sharing this
-    order draw nested, stratified samples."""
-    n = len(proxy)
-    n_strata = max(1, min(int(n_strata), n))
-    rng = np.random.default_rng(seed)
-    ranks = np.argsort(np.argsort(proxy, kind="stable"), kind="stable")
-    strata = (ranks * n_strata) // n                  # (n,) stratum per record
-    perm = rng.permutation(n)
-    sp = strata[perm]
-    within = np.empty(n, np.int64)
-    for s in range(n_strata):
-        members = np.where(sp == s)[0]
-        within[members] = np.arange(len(members))
-    round_pos = rng.permutation(n_strata)             # stratum order per round
-    key = within * n_strata + round_pos[sp]
-    return perm[np.argsort(key, kind="stable")]
 
 
 def _oracle_demand(spec: QuerySpec, n: int) -> int:
@@ -218,9 +194,10 @@ class QuerySession:
             agg = [i for i in idxs if plans[i].kind == "aggregation"]
             if agg:
                 # one stratified order per score group: aggregation members
-                # draw nested samples off the numeric proxy
-                proxy = engine.proxy_for(plans[agg[0]])
-                order = stratified_order(proxy, self.n_strata, self.seed)
+                # draw nested samples off the numeric proxy; the engine
+                # memoizes it per index version
+                order, reused = engine.sample_order(
+                    plans[agg[0]], self.n_strata, self.seed)
                 for i in agg:
                     plans[i].shared_order = order
                 group.shared_order = True
@@ -229,8 +206,9 @@ class QuerySession:
             trace.append(
                 f"group score={label}: specs {idxs}, propagation once per "
                 f"mode {modes}"
-                + (f", shared stratified sample ({self.n_strata} strata) "
-                   f"across {len(agg)} aggregation spec(s)" if agg else ""))
+                + (f", shared stratified sample ({self.n_strata} strata, "
+                   f"{'reused' if reused else 'computed'}) across "
+                   f"{len(agg)} aggregation spec(s)" if agg else ""))
             groups.append(group)
         if sum(len(g.spec_indices) for g in groups) < len(plans):
             trace.append("ungrouped specs execute with the shared label "

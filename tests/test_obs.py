@@ -3,10 +3,10 @@ parse), tracer span nesting + the bounded flight recorder, Chrome trace
 export, disabled-path no-ops, CPU time on nested spans, their export to the
 JAX profiler, backend compiles on the trace, loadgen error-kind
 classification and trace stamping, the scheduler wait split into hold and
-slot, the front end's own spans, and the end-to-end attribution guarantee
-over a live server — every fresh oracle label of a traced request lands in
-exactly one span chain, and the ``/metrics`` exposition agrees with the
-request's own accounting."""
+slot, the front end's own spans, the sample-order memo's counters, and the
+end-to-end attribution guarantee over a live server — every fresh oracle
+label of a traced request lands in exactly one span chain, and the
+``/metrics`` exposition agrees with the request's own accounting."""
 import glob
 import threading
 import time
@@ -526,6 +526,26 @@ def test_traced_request_attributes_every_fresh_label(wl, index):
         assert ei.value.status == 404
     finally:
         server.shutdown()
+
+
+def test_metrics_count_sample_order_computes_and_hits(wl, index):
+    server = QueryServer(QueryEngine(index, wl), port=0,
+                         admission_window=0.0, max_workers=2).start()
+    try:
+        client = QueryClient(server.url)
+        client.wait_ready(30)
+        for _ in range(2):
+            out = client.query(SPEC_DICTS[:1])
+        metrics = parse_prometheus_text(client.metrics())
+    finally:
+        server.shutdown()
+    workload = out["request"]["workload"]
+    # the first aggregation over the score builds its order; every later
+    # plan of it (the server plans, then the session re-plans) reuses it
+    assert metrics[series_key("engine_sample_order_computes_total",
+                              workload=workload)] == 1
+    assert metrics[series_key("engine_sample_order_hits_total",
+                              workload=workload)] >= 1
 
 
 def test_front_end_spans_sit_outside_the_root(wl, index):

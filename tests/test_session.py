@@ -7,8 +7,9 @@ import pytest
 from repro.core import propagation
 from repro.core.engine import QueryEngine, QuerySpec
 from repro.core.index import TastiIndex
+from repro.core.queries.aggregation import stratified_order
 from repro.core.schema import make_workload
-from repro.core.session import QuerySession, stratified_order
+from repro.core.session import QuerySession
 
 
 @pytest.fixture(scope="module")
@@ -44,6 +45,116 @@ def test_stratified_order_tiny_inputs():
     assert len(stratified_order(np.asarray([0.3]), n_strata=10)) == 1
     order = stratified_order(np.arange(5.0), n_strata=10)
     np.testing.assert_array_equal(np.sort(order), np.arange(5))
+
+
+# -- the engine's sample-order memo ----------------------------------------
+def _agg(**kw):
+    return QuerySpec(kind="aggregation", score="score_count", **kw)
+
+
+def test_second_session_reuses_the_sample_order(make_engine):
+    eng = make_engine()
+    first = QuerySession(eng, [_agg(err=0.1)]).plan()
+    second = QuerySession(eng, [_agg(err=0.05, seed=3)]).plan()
+    assert eng.stats["sample_order_computes"] == 1
+    assert eng.stats["sample_order_hits"] == 1
+    expect = stratified_order(eng.proxy_for(first.plans[0]), 10, 0)
+    np.testing.assert_array_equal(second.plans[0].shared_order, expect)
+    assert second.plans[0].shared_order is first.plans[0].shared_order
+    assert "10 strata, computed" in first.trace[1]
+    assert "10 strata, reused" in second.trace[1]
+
+
+@pytest.mark.parametrize("changed", [{"seed": 1}, {"n_strata": 5}])
+def test_other_seed_or_strata_recompute_and_replace(make_engine, changed):
+    eng = make_engine()
+    plan = eng.plan(_agg())
+    args = {"n_strata": 10, "seed": 0}
+    eng.sample_order(plan, **args)
+    order, reused = eng.sample_order(plan, **{**args, **changed})
+    assert not reused
+    np.testing.assert_array_equal(
+        order, stratified_order(eng.proxy_for(plan), **{**args, **changed}))
+    assert eng.sample_order(plan, **{**args, **changed})[1]
+    # the entry was replaced: the first arguments compute again
+    assert not eng.sample_order(plan, **args)[1]
+    assert eng.stats["sample_order_computes"] == 3
+    assert eng.stats["sample_order_hits"] == 1
+
+
+def test_a_crack_recomputes_the_sample_order(make_engine, monkeypatch):
+    import repro.core.engine as engine_mod
+    eng = make_engine()
+    plan = eng.plan(_agg())
+    eng.sample_order(plan)
+    version = eng.index.version
+    assert eng.crack_with(np.arange(40)) > 0
+    assert eng.index.version > version
+    order, reused = eng.sample_order(plan)
+    assert not reused
+    np.testing.assert_array_equal(
+        order, stratified_order(eng.proxy_for(plan), 10, 0))
+
+    # an order computed across a crack is returned but not stored
+    orig = engine_mod.stratified_order
+
+    def cracking(*a, **kw):
+        eng.crack_with(np.arange(40, 80))
+        return orig(*a, **kw)
+
+    eng.sample_order(plan, seed=1)  # replace the entry, so the next computes
+    monkeypatch.setattr(engine_mod, "stratified_order", cracking)
+    eng.sample_order(plan)
+    monkeypatch.setattr(engine_mod, "stratified_order", orig)
+    assert not eng.sample_order(plan)[1]
+    assert eng.stats["sample_order_computes"] == 5
+    assert eng.stats["sample_order_hits"] == 0
+
+
+def test_the_shared_sample_order_is_read_only(make_engine):
+    eng = make_engine()
+    order, _ = eng.sample_order(eng.plan(_agg()))
+    assert not order.flags.writeable
+    with pytest.raises(ValueError):
+        order[0] = 1
+
+
+def test_threaded_sessions_over_one_engine_match_fresh_engines(make_engine):
+    import threading
+    # label reuse off: each session's fresh count is its own, whatever the
+    # other sessions labeled first
+    batches = [[_agg(err=0.1, seed=s, reuse_labels=False),
+                _agg(err=0.06, seed=s + 1, reuse_labels=False)]
+               for s in range(4)]
+    want = [QuerySession(make_engine(), specs).execute().results
+            for specs in batches]
+    eng = make_engine()
+    barrier = threading.Barrier(len(batches))
+    got, errs = [None] * len(batches), []
+
+    def run(i):
+        try:
+            barrier.wait(10)
+            got[i] = QuerySession(eng, batches[i]).execute().results
+        except BaseException as e:  # noqa: BLE001 - surfaced below
+            errs.append(e)
+
+    threads = [threading.Thread(target=run, args=(i,))
+               for i in range(len(batches))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errs
+    for w, g in zip(want, got):
+        for a, b in zip(w, g):
+            assert a.estimate == b.estimate
+            assert a.ci_half_width == b.ci_half_width
+            assert a.n_oracle_fresh == b.n_oracle_fresh
+            np.testing.assert_array_equal(a.raw.sampled_ids, b.raw.sampled_ids)
+    stats = eng.stats
+    assert stats["sample_order_computes"] >= 1
+    assert stats["sample_order_computes"] + stats["sample_order_hits"] == 4
 
 
 # -- accounting under dedup ------------------------------------------------
