@@ -1,7 +1,9 @@
 """After the window: gather what the run served, compare it with the plain
 reference, and reduce the trace to per-layer metrics.
 
-Numbers compared, each against its limit in ``limits.json``:
+Numbers compared, each against its limit in ``limits.json``, and then any
+that the configuration's truth module computes itself (``checks``), each
+against the limit the configuration gives it:
 
 * ``unanswered``: requests due in the window with no 200 answer by the end
   of the wait;
@@ -16,6 +18,10 @@ Numbers compared, each against its limit in ``limits.json``:
 * ``fresh_gap``: fresh labels summed over the answers, and the labels the
   oracle handed out, each against the number of distinct records the
   replays label.
+
+A truth module that declares ``SERVED`` gets what the oracle handed out,
+by id (:class:`mount.ServedOutputs`), as the last argument of its
+``truth_of`` and ``checks``.
 """
 from __future__ import annotations
 
@@ -24,7 +30,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from benchmarks.chip import devtrace, latency, manifest, mount, reference
+from benchmarks.chip import devtrace, idle, latency, manifest, mount, reference
 
 #: records whose top-k the check recomputes
 TOPK_SAMPLE = 4096
@@ -78,6 +84,7 @@ class Loaded:
     traces: list = field(default_factory=list)
     k: int = 0
     oracle_labels: int = 0       # labels the oracle handed out since set-up
+    own_checks: object = None    # () -> the configuration's own numbers
 
     @property
     def n_unanswered(self) -> int:
@@ -87,7 +94,8 @@ class Loaded:
 
 
 def collect(server, engine, corpus, result: dict, plan: dict, args, mix,
-            cfg, truth_mod, t0: float, oracle_labels: int) -> Loaded:
+            cfg, truth_mod, t0: float, oracle_labels: int,
+            served: mount.ServedOutputs = None) -> Loaded:
     rows = {r["index"]: r for r in result["rows"]}
     due = [d for d, _ in plan["requests"]]
     specs = [body["specs"][0] for _, body in plan["requests"]]
@@ -95,14 +103,21 @@ def collect(server, engine, corpus, result: dict, plan: dict, args, mix,
     proxies = {pair: np.asarray(engine.proxy_scores(*pair), np.float64)
                for pair in mount.proxy_modes(mix)}
     traces = [t.to_dict() for t in server.obs.recorder.traces()]
+    # today's truth modules take three arguments; one that declares
+    # ``SERVED`` also takes what the oracle handed out
+    outputs = None if served is None else served.outputs
+    tail = () if served is None else (outputs,)
+    own = getattr(truth_mod, "checks", None)
     return Loaded(
         due=due, specs=specs, rows=rows, wait_end=args.seconds + plan[
             "grace_s"], t0=t0, proxies=proxies,
         rep_ids=np.asarray(index.rep_ids), topk_ids=np.asarray(index.topk_ids),
         topk_d2=np.asarray(index.topk_d2),
-        truth=lambda score: truth_mod.truth_of(corpus, cfg, score),
+        truth=lambda score: truth_mod.truth_of(corpus, cfg, score, *tail),
         topk=topk_sample(index, args.seed),
-        traces=traces, k=index.k, oracle_labels=oracle_labels)
+        traces=traces, k=index.k, oracle_labels=oracle_labels,
+        own_checks=None if own is None else (
+            lambda: own(corpus, cfg, outputs, args.seed)))
 
 
 def proxy_errs(loaded: Loaded, control=None) -> dict:
@@ -151,14 +166,29 @@ def compare(loaded: Loaded, limits: dict) -> dict:
     values["answer_gap"] = gap
     values["fresh_gap"] = float(max(abs(fresh - len(labeled)),
                                     abs(loaded.oracle_labels - len(labeled))))
+    if loaded.own_checks is not None:
+        values.update(loaded.own_checks())
+    return judged(values, limits)
+
+
+def judged(values: dict, limits: dict) -> dict:
+    """Each number beside its limit; refuses a number with no limit and a
+    limit with no number."""
+    if set(values) != set(limits):
+        raise manifest.ManifestError(
+            f"numbers {sorted(set(values) - set(limits))} have no limit, "
+            f"limits {sorted(set(limits) - set(values))} no number")
     return {name: {"value": v, "limit": limits[name]}
             for name, v in values.items()}
 
 
 def layer_metrics(loaded: Loaded, readers: dict, layer: list, trace_dir,
                   sync: float, compiles: int, peaks: dict, base):
-    """(per-layer metrics, {"device": busy and window, "breakdown": ...})."""
-    red = devtrace.Reduction(devtrace.find_xplane(trace_dir))
+    """(per-layer metrics, {"device": busy and window, "breakdown": ...});
+    prints the ``[idle]`` line (:mod:`idle`).  Readers get, among the rest,
+    every kernel's counts by file stem and the trace file's path."""
+    path = devtrace.find_xplane(trace_dir)
+    red = devtrace.Reduction(path)
     offset = sync - red.lo
     requests = []
     for i in range(len(loaded.due)):
@@ -179,12 +209,14 @@ def layer_metrics(loaded: Loaded, readers: dict, layer: list, trace_dir,
     ctx = {"traces": loaded.traces, "requests": requests,
            "n_requests": len(loaded.due), "compiles": compiles,
            "device": red, "propagate_calls": calls, "peaks": peaks,
-           "kernels": {"propagate": manifest.kernel_counts("propagate", base)}}
+           "kernels": manifest.kernels(base), "xplane": path}
     metrics = {}
     for m in layer:
         value = readers[m["name"]].read(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    _, acct = idle.idle_in_request(red, path)
+    print(idle.report_line(acct, red.window_s), flush=True)
     spans = [(s["t0"], s["t1"], s["name"]) for t in loaded.traces
              for s in t["spans"] if s["t1"] is not None]
     extra = {"device": {"busy_s": red.busy_s(), "window_s": red.window_s},

@@ -83,7 +83,7 @@ def control_readings(loaded: check.Loaded) -> dict:
             "control": check.proxy_errs(loaded, reference.propagate_bf16)}
 
 
-def fault_window(cfg, mix, truth, limits, corpus, index, seed: int,
+def fault_window(cfg, mix, truth, limits, corpus, index, served, seed: int,
                  seconds: float) -> dict:
     undo = plant_altered_label(corpus)
     oracle = mount.OracleCounter(corpus)
@@ -102,7 +102,7 @@ def fault_window(cfg, mix, truth, limits, corpus, index, seed: int,
         args = argparse.Namespace(seconds=seconds, seed=seed)
         loaded = check.collect(server, engine, corpus,
                                json.loads(out_path.read_text()), plan, args,
-                               mix, cfg, truth, t0, oracle.labels)
+                               mix, cfg, truth, t0, oracle.labels, served)
         server.shutdown()
         server = None
         fault = check.compare(loaded, limits)
@@ -119,7 +119,8 @@ def fault_window(cfg, mix, truth, limits, corpus, index, seed: int,
 def one_seed(cfg, mix, truth, limits, seed: int, args,
              compile_clock) -> dict:
     clock = mount.Clock()
-    corpus, index = mount.build(cfg, seed, clock)
+    served = mount.served_outputs(truth)
+    corpus, index = mount.build(cfg, seed, clock, served)
     sample = check.topk_sample(index, seed)
     out = {"seed": seed, "build": clock.phases,
            "index": {"program": check.topk_errs(sample),
@@ -128,8 +129,8 @@ def one_seed(cfg, mix, truth, limits, seed: int, args,
     if seed in args.sweep_seeds:
         out["sweep"] = sweep.sweep(cfg, mix, corpus, index, seed)
     if seed in args.fault_seeds:
-        out.update(fault_window(cfg, mix, truth, limits, corpus, index, seed,
-                                args.fault_seconds))
+        out.update(fault_window(cfg, mix, truth, limits, corpus, index,
+                                served, seed, args.fault_seconds))
     if seed in args.crack_seeds:
         out["crack"] = crack.probe(cfg, mix, corpus, index, seed,
                                    args.crack_seconds, compile_clock)
@@ -150,7 +151,7 @@ def main(argv=None) -> None:
     cfg = manifest.config(cell["config"])
     mix = manifest.traffic(cell["traffic"])
     truth = manifest.truth_module(cell["config"])
-    limits = manifest.limits()
+    limits = manifest.cell_limits(cfg, truth)
     enable_compile_cache()
     run.require_chips(cell["chips"])
     compile_clock = run.CompileClock()

@@ -4,11 +4,16 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric sits in files of its own; ``BENCHMARK.json`` names them and this
 module finds them:
 
-* ``configs/<config>.json``: the deployment's sizes, and ``configs/<config>.py``
-  beside it, its plain reference of the corpus's true scores;
+* ``configs/<config>.json``: the deployment's sizes, its test cut
+  (``tiny``, a partial configuration merged over it by the CPU tests only)
+  and any limits of its own; ``configs/<config>.py`` beside it, its plain
+  reference of the corpus's true scores, which may also compute numbers of
+  its own (``CHECKS``, ``checks``) and read what the oracle handed out
+  (``SERVED``);
 * ``traffic/<mix>.json``: the mix's parameters, read by :mod:`schedule`;
 * ``layer_metrics/*.py``: one reader per per-layer metric, each declaring
-  its ``NAME`` (files starting with ``_`` are shared helpers).
+  its ``NAME`` (files starting with ``_`` are shared helpers);
+* ``kernels/<kernel>.py``: one kernel's operation and byte counts.
 
 Unknown keys in a configuration or a mix are refused, so a typo never
 silently falls back to a default.
@@ -24,7 +29,11 @@ ROOT = HERE.parents[1]
 
 CONFIG_KEYS = {"name", "source", "corpus", "records", "reps", "k",
                "embed_dim", "n_train", "triplet_steps", "oracle", "server",
-               "precision", "published", "assumed", "reduced", "deployment"}
+               "precision", "published", "assumed", "reduced", "deployment",
+               "tiny", "limits"}
+#: keys a configuration may leave out
+OPTIONAL_CONFIG_KEYS = {"deployment", "limits"}
+LIMIT_KEYS = {"limit", "why"}
 CORPUS_KEYS = {"class", "size_key", "kwargs"}
 ORACLE_KEYS = {"backend", "replicas", "batch"}
 SERVER_KEYS = {"max_workers", "admission_window_s"}
@@ -72,7 +81,7 @@ def cell(bench: dict, name: str) -> dict:
 def config(name: str, base: pathlib.Path = HERE) -> dict:
     cfg = _read_json(base / "configs" / f"{name}.json")
     _check_keys(f"config {name}", cfg, CONFIG_KEYS,
-                CONFIG_KEYS - {"deployment"})
+                CONFIG_KEYS - OPTIONAL_CONFIG_KEYS)
     if cfg["name"] != name:
         raise ManifestError(f"configs/{name}.json names itself "
                             f"{cfg['name']!r}")
@@ -82,6 +91,11 @@ def config(name: str, base: pathlib.Path = HERE) -> dict:
                 ORACLE_KEYS)
     _check_keys(f"config {name} server", cfg["server"], SERVER_KEYS,
                 SERVER_KEYS)
+    _check_keys(f"config {name} tiny", cfg["tiny"],
+                CONFIG_KEYS - {"name", "tiny"})
+    for limit, entry in cfg.get("limits", {}).items():
+        _check_keys(f"config {name} limit {limit}", entry, LIMIT_KEYS,
+                    LIMIT_KEYS)
     return cfg
 
 
@@ -134,6 +148,12 @@ def layer_metrics(names, base: pathlib.Path = HERE) -> dict:
     return {n: found[n] for n in names}
 
 
+def kernels(base: pathlib.Path = HERE) -> dict:
+    """File stem -> counts module, for every ``kernels/*.py``."""
+    return {path.stem: kernel_counts(path.stem, base)
+            for path in sorted((base / "kernels").glob("*.py"))}
+
+
 def kernel_counts(name: str, base: pathlib.Path = HERE):
     """The operation and byte counts of one kernel (``kernels/<name>.py``)."""
     path = base / "kernels" / f"{name}.py"
@@ -156,3 +176,28 @@ def peaks(device_kind: str, base: pathlib.Path = HERE) -> dict:
 def limits(base: pathlib.Path = HERE) -> dict:
     """The limit of each number the correctness check compares."""
     return _read_json(base / "limits.json")["limits"]
+
+
+def cell_limits(cfg: dict, truth, base: pathlib.Path = HERE) -> dict:
+    """``limits.json``'s limits joined by the configuration's own: one for
+    each number its truth module's ``checks`` reports, named in its
+    ``CHECKS``.  Refuses a name that ``limits.json`` has, a number with no
+    limit and a limit with no number, so that no number goes unchecked."""
+    shared = limits(base)
+    own = cfg.get("limits", {})
+    names = set(getattr(truth, "CHECKS", ()))
+    what = f"config {cfg['name']}"
+    if bool(names) != hasattr(truth, "checks"):
+        raise ManifestError(f"{what}: its truth module defines CHECKS "
+                            "without checks, or checks without CHECKS")
+    clash = (names | set(own)) & set(shared)
+    if clash:
+        raise ManifestError(f"{what}: {sorted(clash)} collide with "
+                            "limits.json")
+    if names - set(own):
+        raise ManifestError(f"{what}: no limit for the numbers "
+                            f"{sorted(names - set(own))}")
+    if set(own) - names:
+        raise ManifestError(f"{what}: limits {sorted(set(own) - names)} "
+                            "have no number in CHECKS")
+    return {**shared, **{n: entry["limit"] for n, entry in own.items()}}

@@ -6,6 +6,11 @@ budgets; the engine, a journaled label store and the HTTP server are the
 stack ``WorkloadRegistry`` builds for a declared workload, with the seed
 passed to the corpus.  Observability stays on, as the server's default.
 
+Where the configuration's truth module reads what the oracle handed out,
+a record of it is installed on the corpus before the index build, so that
+it holds the representatives' labels too; for any other configuration the
+corpus's oracle is left as the program made it.
+
 Warm-up compiles the device propagation for every mode the mix uses, at the
 index's shapes, through the engine's resident state, and leaves the
 engine's proxy cache empty: the window's first query of each (score, mode)
@@ -50,6 +55,33 @@ class OracleCounter:
         corpus.target_dnn_batch = target_dnn_batch
 
 
+class ServedOutputs:
+    """What the corpus's target DNN handed out, by id, set-up included:
+    kept for a configuration whose truth module reads served outputs
+    (``SERVED``), and for no other."""
+
+    def __init__(self):
+        self.outputs: dict = {}
+        self._lock = threading.Lock()
+
+    def wrap(self, corpus) -> None:
+        produce = corpus.target_dnn_batch
+
+        def target_dnn_batch(ids):
+            out = produce(ids)
+            with self._lock:
+                self.outputs.update(zip((int(i) for i in ids), out))
+            return out
+
+        corpus.target_dnn_batch = target_dnn_batch
+
+
+def served_outputs(truth):
+    """A :class:`ServedOutputs` where the configuration's truth module reads
+    what the oracle handed out (``SERVED``), else None."""
+    return ServedOutputs() if getattr(truth, "SERVED", False) else None
+
+
 def make_corpus(cfg: dict, seed: int):
     """The program's workload class named by the configuration, sized and
     seeded by it."""
@@ -60,11 +92,15 @@ def make_corpus(cfg: dict, seed: int):
                   **corpus["kwargs"]})
 
 
-def build(cfg: dict, seed: int, clock: Clock):
-    """The corpus and its index; returns (corpus, index)."""
+def build(cfg: dict, seed: int, clock: Clock,
+          served: ServedOutputs = None):
+    """The corpus and its index; returns (corpus, index).  ``served``, if
+    given, records the oracle's outputs from the index build on."""
     from repro.core.pipeline import build_tasti, cli_tasti_config
 
     corpus = make_corpus(cfg, seed)
+    if served is not None:
+        served.wrap(corpus)
     clock.lap("generate")
     index = build_tasti(corpus, cli_tasti_config(
         n_train=cfg["n_train"], n_reps=cfg["reps"], k=cfg["k"],

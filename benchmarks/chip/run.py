@@ -188,7 +188,7 @@ def run_cell(args, bench: dict, base: pathlib.Path, require) -> dict:
     cfg = manifest.config(cell["config"], base)
     mix = manifest.traffic(cell["traffic"], base)
     truth = manifest.truth_module(cell["config"], base)
-    limits = manifest.limits(base)
+    limits = manifest.cell_limits(cfg, truth, base)
     e2e = [m for m in bench["end_to_end"]
            if args.workload in m.get("workloads", [args.workload])]
     layer = [m for m in bench["per_layer"]
@@ -206,8 +206,9 @@ def run_cell(args, bench: dict, base: pathlib.Path, require) -> dict:
 
     workdir = pathlib.Path(tempfile.mkdtemp(prefix="chip-bench-"))
     server = child = None
+    served = mount.served_outputs(truth)
     try:
-        corpus, index = mount.build(cfg, args.seed, clock)
+        corpus, index = mount.build(cfg, args.seed, clock, served)
         oracle = mount.OracleCounter(corpus)
         server, engine = mount.serve(cfg, corpus, index,
                                      str(workdir / "labels"), clock,
@@ -232,7 +233,7 @@ def run_cell(args, bench: dict, base: pathlib.Path, require) -> dict:
         peak = stats.get("peak_bytes_in_use")
         result = json.loads(out_path.read_text())
         loaded = check.collect(server, engine, corpus, result, plan, args,
-                               mix, cfg, truth, t0, oracle.labels)
+                               mix, cfg, truth, t0, oracle.labels, served)
         server.shutdown()
         server = None
         engine.resident.invalidate()

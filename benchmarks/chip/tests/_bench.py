@@ -13,30 +13,38 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-#: sizes small enough for a test run; widths (k, embedding) as published
-TINY = {"night-street-1m": {"records": 3000},
-        "wikisql-80k": {"records": 4000}}
-TINY_INDEX = {"reps": 150, "n_train": 60, "triplet_steps": 20}
+
+def merged(cfg: dict, part: dict) -> dict:
+    """``cfg`` with ``part`` merged over it key by key, nested objects
+    included."""
+    out = dict(cfg)
+    for key, value in part.items():
+        if isinstance(value, dict) and isinstance(out.get(key), dict):
+            value = merged(out[key], value)
+        out[key] = value
+    return out
 
 
-def tiny_base(tmp: pathlib.Path, cell: str, rate: float = 4.0) -> tuple:
+def tiny_base(tmp: pathlib.Path, cell: str, rate: float = 4.0,
+              root: pathlib.Path = ROOT) -> tuple:
     """(benchmark dict, base dir) holding a copy of the benchmark's files
-    with the cell's configuration cut to a test's size and a CPU entry in
-    the peaks table (never reported: these tests emit no device metric)."""
+    under ``root`` with the cell's configuration cut to its own test size
+    (its ``tiny``) and a CPU entry in the peaks table (never reported:
+    these tests emit no device metric)."""
+    src = root / "benchmarks" / "chip"
     base = tmp / "chip"
     for d in ("configs", "traffic", "layer_metrics", "kernels"):
-        shutil.copytree(HERE / d, base / d)
-    shutil.copy(HERE / "limits.json", base / "limits.json")
-    peaks = json.loads((HERE / "peaks.json").read_text())
+        shutil.copytree(src / d, base / d)
+    shutil.copy(src / "limits.json", base / "limits.json")
+    peaks = json.loads((src / "peaks.json").read_text())
     peaks["devices"]["cpu"] = {"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0,
                                "hbm_bytes": 1.0}
     (base / "peaks.json").write_text(json.dumps(peaks))
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench = json.loads((root / "BENCHMARK.json").read_text())
     entry = [w for w in bench["workloads"] if w["name"] == cell][0]
     cfg_path = base / "configs" / f"{entry['config']}.json"
     cfg = json.loads(cfg_path.read_text())
-    cfg.update(TINY[entry["config"]], **TINY_INDEX)
-    cfg_path.write_text(json.dumps(cfg))
+    cfg_path.write_text(json.dumps(merged(cfg, cfg["tiny"])))
     mix_path = base / "traffic" / f"{entry['traffic']}.json"
     mix = json.loads(mix_path.read_text())
     mix["arrivals"]["rate_qps"] = rate
